@@ -1,0 +1,71 @@
+"""Resolve a cell of BENCHMARK.json into the files that define it.
+
+Everything that belongs to one configuration, traffic mix, metric or
+strategy sits in a file of its own, found by name:
+
+    bench/configs/<config>.json       sizes of the model and population
+    bench/traffic/<traffic>.json      strategy, topology, chunk size
+    bench/limits/<workload>.json      limits of the correctness check
+    bench/metrics/<metric>.py         reader of one per-layer metric
+    bench/reference/<strategy>.py     plain reference of one strategy
+
+so a later change adds a cell by adding files and entries only.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import re
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+# file keys that describe a configuration rather than size it
+META = ("name", "source", "model", "reduced", "assumed")
+
+
+def benchmark(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        return json.load(fh)
+
+
+def read_json(path: str) -> dict:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def cell(name: str, root: str = ROOT) -> dict:
+    """Everything one run of cell `name` needs: the merged sizes
+    (configuration, then traffic), its metrics by kind, its limits."""
+    bm = benchmark(root)
+    wl = {w["name"]: w for w in bm["workloads"]}
+    if name not in wl:
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json")
+    w = wl[name]
+    conf = next(c for c in bm["configs"] if c["name"] == w["config"])
+    sizes = read_json(os.path.join(root, conf["file"]))
+    traffic = read_json(os.path.join(HERE, "traffic", f"{w['traffic']}.json"))
+
+    def applies(metric):
+        return "workloads" not in metric or name in metric["workloads"]
+
+    return {
+        "name": name, "chips": w["chips"], "model": sizes["model"],
+        "sizes": {**{k: v for k, v in sizes.items() if k not in META},
+                  **traffic},
+        "end_to_end": [m for m in bm["end_to_end"] if applies(m)],
+        "per_layer": [m for m in bm["per_layer"] if applies(m)],
+        "limits": read_json(os.path.join(HERE, "limits", f"{name}.json")),
+    }
+
+
+def load_module(kind: str, name: str):
+    """bench/<kind>/<name>.py as a module (the per-layer metric readers)."""
+    path = os.path.join(HERE, kind, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
