@@ -42,8 +42,17 @@ then reported over the honest clients only:
         --strategies pfeddst dfedavgm --adversary-fraction 0.25 \
         --attack sign_flip --defense trimmed_mean \
         --churn-join 0.05 --churn-leave 0.05
+
+Profiling: `--profile-dir DIR` runs the experiments under
+`jax.profiler.trace(DIR)`. Device ops carry the engine's `stage:` and
+`part:` scopes; the host track shows the chunk loop's spans
+(docs/observability.md):
+
+    PYTHONPATH=src python examples/fl_cifar_sim.py --strategies pfeddst \
+        --rounds 10 --profile-dir /tmp/prof
 """
 import argparse
+import contextlib
 
 import jax
 
@@ -134,6 +143,12 @@ def main():
                          "$JAX_COMPILATION_CACHE_DIR, else .jax_cache/): "
                          "every invocation after the first skips the "
                          "multi-second round-jit compile")
+    ap.add_argument("--profile-dir", default=None,
+                    help="run the experiments under jax.profiler.trace "
+                         "into this directory (TensorBoard/Perfetto): "
+                         "device ops carry their stage:/part: scopes, the "
+                         "host track the chunk loop's dispatch/wait/"
+                         "fetch_metrics/consume/evaluate spans")
     ap.add_argument("--chunk-rounds", type=int, default=None,
                     help="run up to N rounds per jit via scan-over-rounds "
                          "(engine.make_multi_round): one compile covers "
@@ -220,22 +235,27 @@ def main():
             eval_mask = ~np.asarray(ts.adversaries)
 
     final = {}
-    for s in args.strategies:
-        trace = args.trace_out
-        if trace and len(args.strategies) > 1:
-            stem, dot, ext = trace.rpartition(".")
-            trace = f"{stem}.{s}.{ext}" if dot else f"{trace}.{s}"
-        hist = run_experiment(
-            s, cfg, fl, data, num_rounds=rounds, eval_every=5,
-            steps_per_epoch=spe, seed=args.seed,
-            trace=trace, trace_stages=args.trace_stages,
-            trace_edges=args.trace_edges, chunk_rounds=chunk_rounds,
-            eval_mask=eval_mask,
-        )
-        if trace:
-            print(f"  trace → {trace}")
-        final[s] = (hist.accuracy[-1], hist.comm_bytes[-1],
-                    hist.net_time_s[-1], hist.device_time_s[-1])
+    profiler = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+                else contextlib.nullcontext())
+    with profiler:
+        for s in args.strategies:
+            trace = args.trace_out
+            if trace and len(args.strategies) > 1:
+                stem, dot, ext = trace.rpartition(".")
+                trace = f"{stem}.{s}.{ext}" if dot else f"{trace}.{s}"
+            hist = run_experiment(
+                s, cfg, fl, data, num_rounds=rounds, eval_every=5,
+                steps_per_epoch=spe, seed=args.seed,
+                trace=trace, trace_stages=args.trace_stages,
+                trace_edges=args.trace_edges, chunk_rounds=chunk_rounds,
+                eval_mask=eval_mask,
+            )
+            if trace:
+                print(f"  trace → {trace}")
+            final[s] = (hist.accuracy[-1], hist.comm_bytes[-1],
+                        hist.net_time_s[-1], hist.device_time_s[-1])
+    if args.profile_dir:
+        print(f"  profile → {args.profile_dir}")
     print(f"\nfinal personalized accuracy ({args.topology} topology, "
           f"{args.link_model} links"
           + (f", {args.device_profile} devices" if args.device_profile

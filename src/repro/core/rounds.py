@@ -56,12 +56,14 @@ from repro.fl.engine import (
     ExchangePlan,
     RoundContext,
     gather_rows,
+    named_stage,
     run_round,
     scan_train,
     scatter_rows,
     where_tree,
 )
 from repro.models.split import merge_params
+from repro.obs.timers import part
 
 # PRNG stream layout of one PFedDST round (order = seed-for-seed parity
 # with the pre-engine implementation).
@@ -121,148 +123,155 @@ def make_pfeddst_stages(
     def score_select(state: PopulationState, ctx: RoundContext):
         # ---- 1. scoring — Eq. 6 restricted to the sampled rows ------------
         m = ctx.m
-        probe = sample_client_batches(ctx.keys["probe"], ctx.data,
-                                      probe_size)
-        params = jax.vmap(merge_params)(state.extractor, state.header)
-        row_params = jax.tree_util.tree_map(
-            lambda x: x[ctx.sampled_idx], params
-        )
-        s_l_rows = loss_disparity_rows(cfg, row_params, probe)   # (n_act, M)
-        s_l = state.loss_matrix.at[ctx.sampled_idx].set(s_l_rows)
-        if hetero is not None:
-            # serve each peer's published snapshot (channel lag picks an
-            # older ring slot); Eq. 7 sees the header actually pulled.
-            # ACTIVE clients' columns are their live state: a participant
-            # exchanges in real time (and mixes its own diagonal from its
-            # live params, never a stale self-snapshot) — only absent
-            # peers are served from the store. Their value-staleness
-            # (deadline misses since last publish) still discounts them
-            # via store.lag below.
-            ctx.store = state.store
-            served, age = store_serve(state.store, state.round, ctx.stale)
-            served = {
-                "e": where_tree(ctx.active, state.extractor, served["e"]),
-                "h": where_tree(ctx.active, state.header, served["h"]),
-            }
-            # a live-served column is current: age 0, like pull_staleness
-            age = jnp.where(ctx.active, 0, age)
-            lag = pull_staleness(state.store, ctx.stale, hetero.depth,
-                                 active=ctx.active)
-            ctx.aux.update(served=served, serve_age=age, pull_lag=lag)
-            header_view = served["h"]
-        else:
-            header_view = state.header
-        cost = fl.comm_cost if ctx.cost is None else ctx.cost
-        flat = flatten_headers(header_view)
-        if ctx.threat is not None and ctx.threat.score_game != "none":
-            # score-integrity adversaries spoof the header/cost view the
-            # scorer sees — repro.openworld.attacks.ThreatState (both the
-            # fused and dense branches below read the spoofed `flat`/`cost`)
-            flat, cost = ctx.threat.game_scores(flat, cost, m)
-        # degenerate populations (M < 2, k < 1) keep the dense path: its
-        # select_peers returns the explicit empty mask for k = 0
-        fused = (use_score_kernel and m > 1 and fl.peers_per_round > 0
-                 and fl.selection not in ("threshold", "random"))
-        # packed-neighbor scoring: the SparseFabric engine path (ctx.nbr
-        # carries the round's padded neighbor lists + per-slot Eq. 9
-        # cost). Pure-jnp O(M·D·P) — independent of use_score_kernel.
-        # Score-integrity adversaries spoof dense cost matrices, so the
-        # sparse branch requires an honest round; threat experiments on
-        # a sparse fabric fall back to the dense branches (which the
-        # engine only feeds at dense-oracle scale, M ≤ DENSE_ORACLE_MAX).
-        sparse = (ctx.nbr is not None and m > 1 and fl.peers_per_round > 0
-                  and fl.selection not in ("threshold", "random")
-                  and ctx.threat is None)
-        if sparse:
-            # ---- 1b/2. packed Eq. 7–9 + top-k (no (M, M) scoring) --------
-            vals, idx, sd_stats = score_topk_sparse(
-                flat, state.last_selected, s_l, state.round,
-                nbr_idx=ctx.nbr["idx"], nbr_valid=ctx.nbr["valid"],
-                alpha=fl.alpha, lam=fl.recency_lambda,
-                comm_cost=ctx.nbr["cost"],
-                k=min(fl.peers_per_round, m - 1),
+        with part("eq6_probe"):
+            probe = sample_client_batches(ctx.keys["probe"], ctx.data,
+                                          probe_size)
+            params = jax.vmap(merge_params)(state.extractor, state.header)
+            row_params = jax.tree_util.tree_map(
+                lambda x: x[ctx.sampled_idx], params
             )
-            mask = topk_to_mask(idx, vals, m)
-            ctx.aux.update(s_l=s_l, s_l_rows=s_l_rows,
-                           topk_vals=vals, topk_idx=idx,
-                           sd_stats=sd_stats)
-            fused = True   # downstream (metrics/context) reads the
-            #                fused aux channel — identical keys
-        elif fused:
-            # ---- 1b/2. fused Eq. 7–9 + top-k (streaming pipeline) --------
-            vals, idx, sd_stats = score_topk(
-                flat, state.last_selected, s_l,
-                state.round, alpha=fl.alpha, lam=fl.recency_lambda,
-                comm_cost=cost, k=min(fl.peers_per_round, m - 1),
-                candidate_mask=ctx.cand,
-            )
-            mask = topk_to_mask(idx, vals, m)
-            ctx.aux.update(s_l=s_l, s_l_rows=s_l_rows,
-                           topk_vals=vals, topk_idx=idx,
-                           sd_stats=sd_stats)
-        else:
-            s_d = header_distance_matrix(
-                flat, use_kernel=use_score_kernel
-            )                                                    # Eq. 7
-            s_p = recency_scores(
-                state.last_selected, state.round, fl.recency_lambda
-            )                                                    # Eq. 8
-            scores = combined_scores(
-                s_l, s_d, s_p, alpha=fl.alpha, comm_cost=cost
-            )                                                    # Eq. 9
-
-            # ---- 2. selection --------------------------------------------
-            if fl.selection == "threshold":
-                mask = select_peers(
-                    scores, threshold=fl.score_threshold,
+            # (n_act, M)
+            s_l_rows = loss_disparity_rows(cfg, row_params, probe)
+            s_l = state.loss_matrix.at[ctx.sampled_idx].set(s_l_rows)
+        with part("eq79_select"):
+            if hetero is not None:
+                # serve each peer's published snapshot (channel lag picks an
+                # older ring slot); Eq. 7 sees the header actually pulled.
+                # ACTIVE clients' columns are their live state: a participant
+                # exchanges in real time (and mixes its own diagonal from its
+                # live params, never a stale self-snapshot) — only absent
+                # peers are served from the store. Their value-staleness
+                # (deadline misses since last publish) still discounts them
+                # via store.lag below.
+                ctx.store = state.store
+                served, age = store_serve(state.store, state.round, ctx.stale)
+                served = {
+                    "e": where_tree(ctx.active, state.extractor, served["e"]),
+                    "h": where_tree(ctx.active, state.header, served["h"]),
+                }
+                # a live-served column is current: age 0, like pull_staleness
+                age = jnp.where(ctx.active, 0, age)
+                lag = pull_staleness(state.store, ctx.stale, hetero.depth,
+                                     active=ctx.active)
+                ctx.aux.update(served=served, serve_age=age, pull_lag=lag)
+                header_view = served["h"]
+            else:
+                header_view = state.header
+            cost = fl.comm_cost if ctx.cost is None else ctx.cost
+            flat = flatten_headers(header_view)
+            if ctx.threat is not None and ctx.threat.score_game != "none":
+                # score-integrity adversaries spoof the header/cost view
+                # the scorer sees — repro.openworld.attacks.ThreatState
+                # (both the fused and dense branches below read the
+                # spoofed `flat`/`cost`)
+                flat, cost = ctx.threat.game_scores(flat, cost, m)
+            # degenerate populations (M < 2, k < 1) keep the dense path: its
+            # select_peers returns the explicit empty mask for k = 0
+            fused = (use_score_kernel and m > 1 and fl.peers_per_round > 0
+                     and fl.selection not in ("threshold", "random"))
+            # packed-neighbor scoring: the SparseFabric engine path (ctx.nbr
+            # carries the round's padded neighbor lists + per-slot Eq. 9
+            # cost). Pure-jnp O(M·D·P) — independent of use_score_kernel.
+            # Score-integrity adversaries spoof dense cost matrices, so the
+            # sparse branch requires an honest round; threat experiments on
+            # a sparse fabric fall back to the dense branches (which the
+            # engine only feeds at dense-oracle scale, M ≤ DENSE_ORACLE_MAX).
+            sparse = (ctx.nbr is not None and m > 1 and fl.peers_per_round > 0
+                      and fl.selection not in ("threshold", "random")
+                      and ctx.threat is None)
+            if sparse:
+                # ---- 1b/2. packed Eq. 7–9 + top-k (no (M, M) scoring) ----
+                vals, idx, sd_stats = score_topk_sparse(
+                    flat, state.last_selected, s_l, state.round,
+                    nbr_idx=ctx.nbr["idx"], nbr_valid=ctx.nbr["valid"],
+                    alpha=fl.alpha, lam=fl.recency_lambda,
+                    comm_cost=ctx.nbr["cost"],
+                    k=min(fl.peers_per_round, m - 1),
+                )
+                mask = topk_to_mask(idx, vals, m)
+                ctx.aux.update(s_l=s_l, s_l_rows=s_l_rows,
+                               topk_vals=vals, topk_idx=idx,
+                               sd_stats=sd_stats)
+                fused = True   # downstream (metrics/context) reads the
+                #                fused aux channel — identical keys
+            elif fused:
+                # ---- 1b/2. fused Eq. 7–9 + top-k (streaming pipeline) ----
+                vals, idx, sd_stats = score_topk(
+                    flat, state.last_selected, s_l,
+                    state.round, alpha=fl.alpha, lam=fl.recency_lambda,
+                    comm_cost=cost, k=min(fl.peers_per_round, m - 1),
                     candidate_mask=ctx.cand,
                 )
-            elif fl.selection == "random":
-                # ablation: identical round structure, random peers
-                rand = jnp.where(
-                    jnp.eye(m, dtype=bool), -1.0,
-                    jax.random.uniform(ctx.keys["rand"], (m, m)),
-                )
-                mask = select_peers(
-                    rand, k=fl.peers_per_round, candidate_mask=ctx.cand
-                )
+                mask = topk_to_mask(idx, vals, m)
+                ctx.aux.update(s_l=s_l, s_l_rows=s_l_rows,
+                               topk_vals=vals, topk_idx=idx,
+                               sd_stats=sd_stats)
             else:
-                mask = select_peers(
-                    scores, k=fl.peers_per_round, candidate_mask=ctx.cand
-                )
-            ctx.aux.update(s_l=s_l, s_l_rows=s_l_rows, s_d=s_d,
-                           scores=scores)
-        mask = mask & ctx.active[:, None]
+                s_d = header_distance_matrix(
+                    flat, use_kernel=use_score_kernel
+                )                                                    # Eq. 7
+                s_p = recency_scores(
+                    state.last_selected, state.round, fl.recency_lambda
+                )                                                    # Eq. 8
+                scores = combined_scores(
+                    s_l, s_d, s_p, alpha=fl.alpha, comm_cost=cost
+                )                                                    # Eq. 9
+
+                # ---- 2. selection ----------------------------------------
+                if fl.selection == "threshold":
+                    mask = select_peers(
+                        scores, threshold=fl.score_threshold,
+                        candidate_mask=ctx.cand,
+                    )
+                elif fl.selection == "random":
+                    # ablation: identical round structure, random peers
+                    rand = jnp.where(
+                        jnp.eye(m, dtype=bool), -1.0,
+                        jax.random.uniform(ctx.keys["rand"], (m, m)),
+                    )
+                    mask = select_peers(
+                        rand, k=fl.peers_per_round, candidate_mask=ctx.cand
+                    )
+                else:
+                    mask = select_peers(
+                        scores, k=fl.peers_per_round, candidate_mask=ctx.cand
+                    )
+                ctx.aux.update(s_l=s_l, s_l_rows=s_l_rows, s_d=s_d,
+                               scores=scores)
+            mask = mask & ctx.active[:, None]
 
         # ---- Eq. 9 score decomposition over the selected edges ------------
         # (repro.obs telemetry, through the jit-safe ctx.record channel).
         # The dense path reduces matrices it already holds; the fused path
         # re-derives the components for the selected (M, k) pairs only —
         # O(M·k·P) gathers, never an (M, M) matrix.
-        n_sel = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
-        if fused:
-            comp = selected_components(
-                flat, state.last_selected, s_l,
-                state.round, ctx.aux["topk_idx"], alpha=fl.alpha,
-                lam=fl.recency_lambda, comm_cost=cost,
-            )
-            # scatter-valid ∧ active rows — the same entries `mask` keeps,
-            # so the edge count matches the dense reduction exactly
-            valid = ((ctx.aux["topk_vals"] > NEG / 2)
-                     & ctx.active[:, None])
-            for comp_name in ("s_l", "s_d", "s_p", "cost"):
-                ctx.record(
-                    f"sel_{comp_name}_mean",
-                    jnp.sum(jnp.where(valid, comp[comp_name], 0.0)) / n_sel,
+        with part("telemetry"):
+            n_sel = jnp.maximum(jnp.sum(mask), 1).astype(jnp.float32)
+            if fused:
+                comp = selected_components(
+                    flat, state.last_selected, s_l,
+                    state.round, ctx.aux["topk_idx"], alpha=fl.alpha,
+                    lam=fl.recency_lambda, comm_cost=cost,
                 )
-        else:
-            for comp_name, mat in (("s_l", s_l), ("s_d", s_d),
-                                   ("s_p", s_p),
-                                   ("cost", as_cost_matrix(cost, m))):
-                ctx.record(
-                    f"sel_{comp_name}_mean",
-                    jnp.sum(jnp.where(mask, mat, 0.0)) / n_sel,
-                )
+                # scatter-valid ∧ active rows — the same entries `mask`
+                # keeps, so the edge count matches the dense reduction
+                # exactly
+                valid = ((ctx.aux["topk_vals"] > NEG / 2)
+                         & ctx.active[:, None])
+                for comp_name in ("s_l", "s_d", "s_p", "cost"):
+                    ctx.record(
+                        f"sel_{comp_name}_mean",
+                        jnp.sum(jnp.where(valid, comp[comp_name], 0.0))
+                        / n_sel,
+                    )
+            else:
+                for comp_name, mat in (("s_l", s_l), ("s_d", s_d),
+                                       ("s_p", s_p),
+                                       ("cost", as_cost_matrix(cost, m))):
+                    ctx.record(
+                        f"sel_{comp_name}_mean",
+                        jnp.sum(jnp.where(mask, mat, 0.0)) / n_sel,
+                    )
 
         if hetero is not None:
             lag = ctx.aux["pull_lag"]
@@ -378,31 +387,33 @@ def make_pfeddst_stages(
         loss_matrix = jnp.where(
             ctx.active[:, None], ctx.aux["s_l"], state.loss_matrix
         )
-        if "scores" in ctx.aux:
-            scores, s_d = ctx.aux["scores"], ctx.aux["s_d"]
-            sel_sum = jnp.sum(jnp.where(mask, scores, 0.0))
-            sd_sum, sd_trace = jnp.sum(s_d), jnp.trace(s_d)
-        else:
-            # fused pipeline: the selected scores ARE the emitted top-k
-            # values (mask = scatter of the valid indices ∧ active rows),
-            # and the s_d stats come from the kernel's row statistics.
-            # On the packed-neighbor branch the row sums cover the
-            # NEIGHBORHOOD only (score_topk_sparse docstring), so
-            # s_d_offdiag_mean reads lower there — same normalizer,
-            # fewer summed pairs — and is not comparable across fabrics.
-            vals = ctx.aux["topk_vals"]
-            sel = (vals > NEG / 2) & ctx.active[:, None]
-            sel_sum = jnp.sum(jnp.where(sel, vals, 0.0))
-            sd_sum = jnp.sum(ctx.aux["sd_stats"][:, 0])
-            sd_trace = jnp.sum(ctx.aux["sd_stats"][:, 1])
-        ctx.metrics.update(
-            mean_selected_score=sel_sum / jnp.maximum(jnp.sum(mask), 1),
-            # mean over the rows actually evaluated this round (the
-            # sampled clients) — unsampled rows are served from cache
-            s_l_mean=jnp.mean(ctx.aux["s_l_rows"]),
-            s_d_offdiag_mean=(sd_sum - sd_trace) / (m * (m - 1)),
-            select_mask=mask,
-        )
+        with part("telemetry"):
+            if "scores" in ctx.aux:
+                scores, s_d = ctx.aux["scores"], ctx.aux["s_d"]
+                sel_sum = jnp.sum(jnp.where(mask, scores, 0.0))
+                sd_sum, sd_trace = jnp.sum(s_d), jnp.trace(s_d)
+            else:
+                # fused pipeline: the selected scores ARE the emitted top-k
+                # values (mask = scatter of the valid indices ∧ active
+                # rows), and the s_d stats come from the kernel's row
+                # statistics. On the packed-neighbor branch the row sums
+                # cover the NEIGHBORHOOD only (score_topk_sparse
+                # docstring), so s_d_offdiag_mean reads lower there — same
+                # normalizer, fewer summed pairs — and is not comparable
+                # across fabrics.
+                vals = ctx.aux["topk_vals"]
+                sel = (vals > NEG / 2) & ctx.active[:, None]
+                sel_sum = jnp.sum(jnp.where(sel, vals, 0.0))
+                sd_sum = jnp.sum(ctx.aux["sd_stats"][:, 0])
+                sd_trace = jnp.sum(ctx.aux["sd_stats"][:, 1])
+            ctx.metrics.update(
+                mean_selected_score=sel_sum / jnp.maximum(jnp.sum(mask), 1),
+                # mean over the rows actually evaluated this round (the
+                # sampled clients) — unsampled rows are served from cache
+                s_l_mean=jnp.mean(ctx.aux["s_l_rows"]),
+                s_d_offdiag_mean=(sd_sum - sd_trace) / (m * (m - 1)),
+                select_mask=mask,
+            )
         return state._replace(
             loss_matrix=loss_matrix,
             last_selected=update_recency(
@@ -411,8 +422,15 @@ def make_pfeddst_stages(
             round=state.round + 1,
         )
 
+    # the stage scopes' names, which the device profiles read: written
+    # out so that renaming a closure cannot change them
+    stages = (named_stage(score_select, "score_select"),
+              named_stage(aggregate, "aggregate"),
+              named_stage(phase_e, "phase_e"),
+              named_stage(phase_h, "phase_h"),
+              named_stage(update_context, "update_context"))
     if hetero is None:
-        return (score_select, aggregate, phase_e, phase_h, update_context)
+        return stages
 
     def publish(state: PopulationState, ctx: RoundContext):
         # ---- 6.5 publish — completers' snapshots enter the ring -----------
@@ -426,8 +444,7 @@ def make_pfeddst_stages(
         return state._replace(store=store)
 
     gate = stage_deadline_gate(hetero, get_round=lambda s: s.round)
-    return (gate, score_select, aggregate, phase_e, phase_h, publish,
-            update_context)
+    return (gate, *stages[:4], named_stage(publish, "publish"), stages[4])
 
 
 def pfeddst_round(

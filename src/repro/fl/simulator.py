@@ -40,7 +40,12 @@ from repro.fl.strategies import Strategy, make_strategy
 from repro.models import model as model_mod
 from repro.models.split import merge_params, split_params
 from repro.obs.registry import scalar_metrics
-from repro.obs.timers import RoundClock, StageTimes, instrument_stages
+from repro.obs.timers import (
+    CompileLog,
+    RoundClock,
+    StageTimes,
+    instrument_stages,
+)
 from repro.obs.trace import (
     TraceWriter,
     header_record,
@@ -256,6 +261,15 @@ def run_experiment(
     selected-edge lists in the round records (O(edges) JSON per round).
     With trace=None the run is byte-identical to the untraced path.
 
+    The loop opens host spans on the profiler's host track: `dispatch`,
+    `wait` and `fetch_metrics` around each jitted call (chunk or round),
+    its fence and the copy of its metrics to the host, `consume` around
+    the per-round bookkeeping and trace writing, and `evaluate` (inside
+    it) around head fine-tuning and evaluation. Under `jax.profiler.
+    trace` they attribute the host time between device work. A
+    `CompileLog` is open for the whole run; the trace's summary carries
+    it as `compile_log`.
+
     chunk_rounds > 1 drives CHUNKED execution: `engine.make_multi_round`
     runs up to `chunk_rounds` rounds inside one jit (lax.scan, donated
     population buffers) and the stacked per-round metrics are unstacked
@@ -269,6 +283,18 @@ def run_experiment(
     CHUNK (one compile + `chunk_rounds` executed rounds); trace records
     of that chunk carry compile_round=True.
     """
+    with CompileLog() as compile_log:
+        return _run(strategy_name, cfg, fl, data, compile_log,
+                    num_rounds=num_rounds, eval_every=eval_every,
+                    steps_per_epoch=steps_per_epoch, seed=seed,
+                    verbose=verbose, trace=trace, trace_stages=trace_stages,
+                    trace_edges=trace_edges, chunk_rounds=chunk_rounds,
+                    eval_mask=eval_mask)
+
+
+def _run(strategy_name, cfg, fl, data, compile_log, *, num_rounds,
+         eval_every, steps_per_epoch, seed, verbose, trace, trace_stages,
+         trace_edges, chunk_rounds, eval_mask) -> History:
     strat = make_strategy(strategy_name, cfg, fl, steps_per_epoch)
     key = jax.random.PRNGKey(seed)
     k_init, k_rounds, k_ft = jax.random.split(key, 3)
@@ -390,17 +416,19 @@ def run_experiment(
 
         eval_point = None
         if (r + 1) % eval_every == 0 or r == num_rounds - 1:
-            params = strat.params_for_eval(state)
-            if strat.needs_head_finetune:
-                # fold in the round index: each eval point personalizes on
-                # fresh batch draws instead of replaying the same k_ft ones
-                params = _finetune_heads(
-                    cfg, fl, params, data["train_x"], data["train_y"],
-                    jax.random.fold_in(k_ft, r),
+            with jax.profiler.TraceAnnotation("evaluate"):
+                params = strat.params_for_eval(state)
+                if strat.needs_head_finetune:
+                    # fold in the round index: each eval point personalizes
+                    # on fresh batch draws instead of replaying the same
+                    # k_ft ones
+                    params = _finetune_heads(
+                        cfg, fl, params, data["train_x"], data["train_y"],
+                        jax.random.fold_in(k_ft, r),
+                    )
+                acc, accs = evaluate_population(
+                    cfg, params, data["test_x"], data["test_y"]
                 )
-            acc, accs = evaluate_population(
-                cfg, params, data["test_x"], data["test_y"]
-            )
             if eval_mask is not None:
                 kept = np.asarray(accs)[np.asarray(eval_mask, bool)]
                 acc = float(kept.mean()) if kept.size else float("nan")
@@ -467,31 +495,40 @@ def run_experiment(
                         strat.spec, fl, strat.fabric, chunk_rounds=size
                     )
                 with clock.chunk(size):
-                    state, stacked = fn(state, train_data, k_rounds,
-                                        jnp.int32(r0))
-                    jax.block_until_ready((state, stacked))
+                    with jax.profiler.TraceAnnotation("dispatch"):
+                        state, stacked = fn(state, train_data, k_rounds,
+                                            jnp.int32(r0))
+                    with jax.profiler.TraceAnnotation("wait"):
+                        jax.block_until_ready((state, stacked))
                 if chunk_i == 0:
                     hist.compile_s = clock.compile_s
-                stacked = jax.device_get(stacked)
-                for i in range(size):
-                    consume_round(
-                        r0 + i,
-                        jax.tree_util.tree_map(lambda v, i=i: v[i],
-                                               stacked),
-                        compile_round=(chunk_i == 0),
-                    )
+                with jax.profiler.TraceAnnotation("fetch_metrics"):
+                    stacked = jax.device_get(stacked)
+                with jax.profiler.TraceAnnotation("consume"):
+                    for i in range(size):
+                        consume_round(
+                            r0 + i,
+                            jax.tree_util.tree_map(lambda v, i=i: v[i],
+                                                   stacked),
+                            compile_round=(chunk_i == 0),
+                        )
                 r0 += size
                 chunk_i += 1
         else:
             for r in range(num_rounds):
                 k_r = jax.random.fold_in(k_rounds, r)
                 with clock.round():
-                    state, metrics = round_jit(state, train_data, k_r)
+                    with jax.profiler.TraceAnnotation("dispatch"):
+                        state, metrics = round_jit(state, train_data, k_r)
                     # fence so the clock sees execution, not async dispatch
-                    jax.block_until_ready((state, metrics))
+                    with jax.profiler.TraceAnnotation("wait"):
+                        jax.block_until_ready((state, metrics))
                 if r == 0:
                     hist.compile_s = clock.compile_s
-                consume_round(r, metrics, compile_round=(r == 0))
+                with jax.profiler.TraceAnnotation("fetch_metrics"):
+                    metrics = jax.device_get(metrics)
+                with jax.profiler.TraceAnnotation("consume"):
+                    consume_round(r, metrics, compile_round=(r == 0))
 
     if tracer is not None:
         if graph.rounds > 0:
@@ -500,6 +537,7 @@ def run_experiment(
             rounds=num_rounds, wall_s=clock.elapsed(),
             compile_s=clock.compile_s,
             final_accuracy=hist.accuracy[-1] if hist.accuracy else None,
+            compile_log=compile_log.summary(),
         ))
         tracer.close()
     hist.state = state

@@ -6,7 +6,7 @@ trace           schema-versioned per-round JSONL traces: writer, record
                 builders, validator, reader
 timers          host-side compile/steady wall-time attribution: per-stage
                 instrumentation (unjitted rounds), whole-round clock,
-                named_scope/TraceAnnotation helpers
+                compile log, named_scope/TraceAnnotation helpers
 selection_probe opt-in dense Eq. 9 score decomposition, fused-kernel
                 parity checks, cumulative selection-graph export
 
@@ -28,10 +28,12 @@ from repro.obs.selection_probe import (
     probe_topk,
 )
 from repro.obs.timers import (
+    CompileLog,
     RoundClock,
     StageTimes,
     annotate,
     instrument_stages,
+    part,
     stage_name,
 )
 from repro.obs.trace import (
@@ -57,10 +59,12 @@ __all__ = [
     "components_of_selected",
     "decompose_scores",
     "probe_topk",
+    "CompileLog",
     "RoundClock",
     "StageTimes",
     "annotate",
     "instrument_stages",
+    "part",
     "stage_name",
     "SCHEMA_VERSION",
     "TraceWriter",

@@ -16,11 +16,15 @@ block. The helpers here make wall-time attribution explicit:
 * `RoundClock` — the whole-round variant the simulator threads through
   `run_experiment`: round 0's wall (compile-dominated) lands in
   `compile_s`, later rounds accumulate into `steady_s`.
+* `CompileLog` — what building the programs cost: JAX's own trace,
+  lowering, backend-compile and compile-cache durations, as unions of
+  their intervals while the log is open.
 
 `jax.named_scope` is also applied by the engine itself around every
-stage (jit-compatible: it only attaches XLA metadata), so device
-profiles collected with `jax.profiler` group ops by stage even in the
-fully-jitted path.
+stage (`stage:<name>`, jit-compatible: it only attaches XLA metadata),
+so device profiles collected with `jax.profiler` group ops by stage even
+in the fully-jitted path; `part(name)` opens the `part:<name>` scopes
+that split a stage further.
 """
 from __future__ import annotations
 
@@ -36,6 +40,13 @@ def stage_name(stage) -> str:
     attribute the stage factories attach, falling back to __name__."""
     return getattr(stage, "stage_name",
                    getattr(stage, "__name__", "stage"))
+
+
+def part(name: str):
+    """`part:<name>` scope around one part of a stage's work: XLA metadata
+    only, so inside the engine's `stage:<stage>` scope a device op's
+    op_name reads `.../stage:<stage>/part:<name>/...`."""
+    return jax.named_scope(f"part:{name}")
 
 
 @contextmanager
@@ -171,3 +182,71 @@ class RoundClock:
 
     def elapsed(self) -> float:
         return self.steady_s
+
+
+TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration")
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EVENTS = TRACE_EVENTS + (BACKEND_EVENT, CACHE_EVENT)
+_open_logs: list = []
+
+
+def _hear(event, duration, **kw):
+    if _open_logs and event in _EVENTS:
+        end = time.perf_counter()
+        for log in tuple(_open_logs):
+            log.events.append((event, end - duration, end))
+
+
+class CompileLog:
+    """The time JAX spends building programs while the log is open.
+
+    JAX reports the duration of every trace, lowering, backend compile
+    and persistent-cache retrieval through `jax.monitoring`; each event
+    becomes an interval ending when it is heard. Nested jits trace
+    inside their callers' traces and a cache retrieval lies inside its
+    backend compile, so the seconds are unions of intervals, not sums:
+
+        trace_s     trace and lowering to StableHLO
+        compile_s   XLA compiles and cache loads
+        compiles    programs XLA compiled
+        cache_hits  programs loaded from the persistent cache
+
+    Listeners cannot be unregistered, so one is registered per process
+    and feeds the logs that are open. Use as a context manager.
+    """
+    _registered = False
+
+    def __init__(self):
+        self.events: list = []
+        if not CompileLog._registered:
+            jax.monitoring.register_event_duration_secs_listener(_hear)
+            CompileLog._registered = True
+
+    def __enter__(self):
+        _open_logs.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        _open_logs.remove(self)
+
+    def summary(self) -> dict:
+        def union(names):
+            return _union([(a, b) for e, a, b in self.events if e in names])
+
+        backend = sum(1 for e, _, _ in self.events if e == BACKEND_EVENT)
+        hits = sum(1 for e, _, _ in self.events if e == CACHE_EVENT)
+        return {"trace_s": union(TRACE_EVENTS),
+                "compile_s": union((BACKEND_EVENT, CACHE_EVENT)),
+                "compiles": backend - hits, "cache_hits": hits}
+
+
+def _union(intervals) -> float:
+    """Length of the union of (start, end) intervals."""
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total

@@ -25,7 +25,10 @@ Record shapes (all extra keys allowed; required keys validated):
                    eval {accuracy, train_loss} | absent
   selection_graph  type, num_clients, rounds, edges [[i, j, count]...],
                    churn [float]  — per-round selection Jaccard churn
-  summary          type, rounds, wall_s, compile_s
+  summary          type, rounds, wall_s, compile_s,
+                   compile_log {trace_s, compile_s, compiles,
+                   cache_hits} | absent — obs.timers.CompileLog over
+                   the run
 """
 from __future__ import annotations
 

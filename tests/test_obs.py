@@ -19,6 +19,7 @@ from repro.fl.simulator import History
 from repro.kernels.ops import resolve_select_impl, select_topk
 from repro.obs import (
     DEFAULT_REGISTRY,
+    CompileLog,
     MetricRegistry,
     RoundClock,
     SelectionGraph,
@@ -225,6 +226,55 @@ def test_instrument_stages_times_and_names():
     summary = times.summary()
     assert set(summary) == {"alpha", "custom_beta"}
     assert all(s["calls"] == 2 for s in summary.values())
+
+
+PFEDDST_STAGES = ("score_select", "aggregate", "phase_e", "phase_h",
+                  "update_context")
+
+
+@pytest.mark.parametrize("name,stages", [
+    ("pfeddst", PFEDDST_STAGES),
+    ("pfeddst_async", ("deadline_gate", *PFEDDST_STAGES[:4], "publish",
+                       PFEDDST_STAGES[4])),
+])
+def test_pfeddst_stage_names(name, stages):
+    """The stage scopes' names are fixed by the stages, not by the
+    closures' __name__s: device profiles read them."""
+    from repro.configs import get_config
+    from repro.configs.base import FLConfig
+    from repro.fl.strategies import make_spec
+
+    cfg = get_config("resnet18-cifar").reduced()
+    spec = make_spec(name, cfg, FLConfig(num_clients=4, peers_per_round=1),
+                     steps_per_epoch=1)
+    assert tuple(stage_name(s) for s in spec.stages) == stages
+    assert all(s.stage_name == stage_name(s) for s in spec.stages)
+
+
+def test_compile_log_takes_unions_of_nested_traces():
+    """A jit that calls a nested jit traces the inner one inside its own
+    trace: the log's trace_s is the union, no longer than the wall of
+    lowering and compiling, and the compile is counted."""
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x + 1)
+
+    x = jnp.arange(4.0)
+    with CompileLog() as log:
+        t0 = time.perf_counter()
+        outer.lower(x).compile()
+        wall = time.perf_counter() - t0
+    s = log.summary()
+    assert 0 < s["trace_s"] <= wall
+    assert 0 < s["compile_s"] <= wall
+    assert s["compiles"] >= 1 and s["cache_hits"] == 0
+    # closed: later compiles are not heard
+    jax.jit(lambda v: v - 1).lower(x).compile()
+    assert log.summary() == s
 
 
 def test_round_clock_compile_steady_split():
@@ -499,11 +549,14 @@ def test_trace_report_renders_and_validates(tmp_path):
         g = SelectionGraph(4)
         g.observe(np.asarray([[0, 1]]))
         tw.write(g.to_record())
-        tw.write(summary_record(rounds=2, wall_s=0.2, compile_s=1.0))
+        tw.write(summary_record(
+            rounds=2, wall_s=0.2, compile_s=1.0,
+            compile_log={"trace_s": 0.5, "compile_s": 0.8, "compiles": 3,
+                         "cache_hits": 1}))
     assert tr.main([path, "--validate"]) == 0
     text = tr.report(read_trace(path))
     for token in ("strategy=pfeddst", "phase_e", "Eq. 9", "selection graph",
-                  "summary"):
+                  "summary", "3 compiled, 1 from the cache"):
         assert token in text, token
     # schema violations -> nonzero exit under --validate
     with open(path, "a") as fh:
@@ -515,7 +568,8 @@ def test_trace_report_renders_and_validates(tmp_path):
 # traced simulator run vs the golden trace (slow tier)
 # ---------------------------------------------------------------------------
 
-HOST_TIME_KEYS = {"wall_s", "compile_s", "first_s", "steady_s"}
+HOST_TIME_KEYS = {"wall_s", "compile_s", "first_s", "steady_s",
+                  "compile_log"}
 
 
 def _strip_host_time(obj):

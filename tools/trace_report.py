@@ -11,6 +11,8 @@ Sections (each skipped when the trace lacks the records that feed it):
     plus first→last drift (is selection converging on loss-disparate,
     dissimilar peers as the paper argues?)
   * selection graph — top selected edges by frequency, mean churn
+  * summary — steady and first-chunk walls, and the time spent
+    tracing, lowering and compiling programs (`compile_log`)
 
 --validate re-checks every record against the obs.trace schema and
 exits nonzero on any error (the CI artifact gate).
@@ -117,6 +119,14 @@ def report(records) -> str:
             f"\nsummary: {s['rounds']} rounds, steady wall "
             f"{s['wall_s']:.2f}s, compile {s['compile_s']:.2f}s"
         )
+        log = s.get("compile_log")
+        if log:
+            out.append(
+                f"programs built: trace+lower {log['trace_s']:.2f}s, "
+                f"XLA compile+cache load {log['compile_s']:.2f}s, "
+                f"{log['compiles']} compiled, {log['cache_hits']} from "
+                "the cache"
+            )
     return "\n".join(out)
 
 
