@@ -99,15 +99,34 @@ def loss_fn(cfg: ModelConfig, params, batch, *, backend="auto", remat=False):
     return total, metrics
 
 
-def eval_loss(cfg: ModelConfig, params, batch, *, backend="auto"):
-    """Pure task loss (no aux) — the s_l scoring signal (paper Eq. 6)."""
+def _task_loss(cfg: ModelConfig, logits, batch):
+    """Task loss of one batch's logits: no aux, f32 cross-entropy."""
     if cfg.family == "cnn":
-        logits, _ = forward(cfg, params, batch)
         return cross_entropy_loss(logits, batch["labels"])
-    logits, _ = forward(cfg, params, batch, backend=backend)
     tokens = batch["tokens"]
     p = logits.shape[1] - tokens.shape[1]
     return cross_entropy_loss(logits[:, p : p + tokens.shape[1] - 1], tokens[:, 1:])
+
+
+def eval_loss(cfg: ModelConfig, params, batch, *, backend="auto"):
+    """Pure task loss (no aux) — the s_l scoring signal (paper Eq. 6)."""
+    logits, _ = forward(cfg, params, batch, backend=backend)
+    return _task_loss(cfg, logits, batch)
+
+
+def client_losses(cfg: ModelConfig, logits, batches):
+    """`eval_loss` of each of M clients' batches, from one flat forward.
+
+    batches: dict of (M, B, ...) arrays; logits: `forward` of one model
+    on their (M·B, ...) flattening. → (M,) float32, entry j the task loss
+    over client j's B examples alone. MoE capacity groups are formed over
+    the flat batch, so when B·S is not a multiple of the group size a
+    client's tokens share expert capacity with its neighbours' in the
+    batch.
+    """
+    m, b = jax.tree_util.tree_leaves(batches)[0].shape[:2]
+    logits = logits.reshape((m, b) + logits.shape[1:])
+    return jax.vmap(lambda lg, bt: _task_loss(cfg, lg, bt))(logits, batches)
 
 
 def accuracy(cfg: ModelConfig, params, batch):

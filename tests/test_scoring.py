@@ -1,4 +1,6 @@
 """Unit + property tests for the PFedDST scoring signals (paper Eq. 6–9)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,11 +8,14 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
+from repro.configs import get_config
 from repro.core.scoring import (
     flatten_headers,
     header_distance_matrix,
     header_gram_tree,
     loss_disparity_matrix,
+    loss_disparity_row,
+    loss_disparity_rows,
     recency_scores,
 )
 from repro.core.selection import combined_scores
@@ -120,6 +125,72 @@ def test_loss_disparity_diag_vs_offdiag(tiny_cnn, key):
     assert L.shape == (m, m)
     assert bool(jnp.all(jnp.isfinite(L)))
     assert bool(jnp.all(L >= 0))
+
+
+def _vmapped_rows(cfg, rows, probe):
+    """The oracle: each row's eval_loss vmapped over the M probe batches."""
+    return jax.lax.map(
+        lambda p: jax.vmap(lambda b: model_mod.eval_loss(cfg, p, b))(probe),
+        rows,
+    )
+
+
+def _population_and_probe(cfg, key, m, b):
+    params = jax.vmap(lambda k: model_mod.init_params(cfg, k))(
+        jax.random.split(key, m)
+    )
+    ki, kl = jax.random.split(key)
+    if cfg.family == "cnn":
+        probe = {
+            "images": jax.random.normal(
+                ki, (m, b, cfg.image_size, cfg.image_size, 3)
+            ),
+            "labels": jax.random.randint(kl, (m, b), 0, cfg.num_classes),
+        }
+    else:
+        probe = {"tokens": jax.random.randint(kl, (m, b, 16), 0,
+                                              cfg.vocab_size)}
+    return params, probe
+
+
+@pytest.mark.parametrize("dtype,rtol", [("float32", 0.0),
+                                        ("bfloat16", 1e-3)])
+def test_loss_disparity_rows_match_vmapped_rows(tiny_cnn, key, dtype, rtol):
+    """One flat forward a row gives every (row, client) loss the vmapped
+    row gave: bitwise in float32, within bf16 fusion noise in bfloat16."""
+    cfg = dataclasses.replace(tiny_cnn, dtype=dtype)
+    m, b, r = 6, 8, 3
+    params, probe = _population_and_probe(cfg, key, m, b)
+    rows = jax.tree_util.tree_map(lambda x: x[:r], params)
+    want = np.asarray(jax.jit(
+        lambda p, x: _vmapped_rows(cfg, p, x))(rows, probe))
+    got = np.asarray(jax.jit(
+        lambda p, x: loss_disparity_rows(cfg, p, x))(rows, probe))
+    assert got.shape == (r, m) and got.dtype == np.float32
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, rtol=rtol, atol=0)
+    # the single-row form, against the vmapped row outside the loop
+    p1 = jax.tree_util.tree_map(lambda x: x[1], params)
+    want_one = np.asarray(jax.jit(lambda p, x: jax.vmap(
+        lambda bt: model_mod.eval_loss(cfg, p, bt))(x))(p1, probe))
+    one = np.asarray(jax.jit(
+        lambda p, x: loss_disparity_row(cfg, p, x))(p1, probe))
+    np.testing.assert_allclose(one, want_one, rtol=rtol, atol=0)
+
+
+def test_loss_disparity_matrix_lm_matches_vmapped_rows(key):
+    """The LM path (`launch/steps.fed_round_step` passes (M, Bp, S)
+    tokens): per-client shifted-token losses of one flat forward."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    m, b = 3, 2
+    params, probe = _population_and_probe(cfg, key, m, b)
+    want = np.asarray(jax.jit(
+        lambda p, x: _vmapped_rows(cfg, p, x))(params, probe))
+    got = np.asarray(jax.jit(
+        lambda p, x: loss_disparity_matrix(cfg, p, x))(params, probe))
+    assert got.shape == (m, m)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=0)
 
 
 # ---------------------------------------------------------------------------
