@@ -6,7 +6,8 @@
 In one process, on the chip: for each seed, the program's first chunk
 and the reference over the same rounds, compared as a run compares them
 (the lower readings); on the first `--control` seeds the control, the
-reference in float8 in the program's place (the upper readings); and
+reference in the precision below the configuration's, in the program's
+place (the upper readings; the model's `control_quant`); and
 each planted fault of the program on the same seeds. The benchmark's own
 runs never do this. Prints one JSON object per reading, then the table.
 """
@@ -25,7 +26,6 @@ sys.path.insert(1, os.path.join(os.path.dirname(HERE), "src"))
 import faults  # noqa: E402
 import harness  # noqa: E402
 import workload  # noqa: E402
-from reference import resnet  # noqa: E402
 
 
 def readings(cell, seeds, n_control, fault_names, out=print, n_fault=3,
@@ -49,7 +49,7 @@ def readings(cell, seeds, n_control, fault_names, out=print, n_fault=3,
         out(json.dumps(row))
         rows.append(row)
         if i < n_control:
-            ctl = prog.reference(data, keys, quant=resnet.fp8_quant)
+            ctl = prog.reference(data, keys, quant=prog.model.control_quant)
             row = {"kind": "control", "seed": seed,
                    **prog.ref.compare(ctl, ref)}
             out(json.dumps(row))

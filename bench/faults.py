@@ -1,10 +1,10 @@
 """Faults planted in the program, to show that the comparison catches
 them (bench/calibrate.py on the chip, bench/tests on the CPU).
 
-    half_batch  local training takes the mean loss over the first half
-                of each batch and leaves the rest out (the whole batch
-                still runs forward, so the program keeps its shapes and
-                fits the chip at M = 100)
+    half_batch  local training takes the task loss over the first half
+                of each batch's rows and leaves the rest out (the whole
+                batch still runs forward, so the program keeps its
+                shapes and fits the chip at M = 100)
     answer      an answer altered where it is produced: pfeddst's top-k
                 selection points every pick at the next client; dispfl's
                 evolved mask has every 64th entry flipped
@@ -32,13 +32,13 @@ def _patched(module, name, value):
 
 
 def _half_batch():
-    from repro.models import layers, model
+    from repro.models import model
 
     def half(cfg, params, batch, **kw):
         logits, _ = model.forward(cfg, params, batch, **kw)
-        labels = batch["labels"]
-        keep = jnp.arange(labels.shape[0]) < labels.shape[0] // 2
-        loss = layers.cross_entropy_loss(logits, labels, keep)
+        n = logits.shape[0] // 2
+        loss = model._task_loss(cfg, logits[:n], jax.tree_util.tree_map(
+            lambda x: x[:n], batch))
         return loss, {"loss": loss}
 
     return _patched(model, "loss_fn", half)

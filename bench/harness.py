@@ -9,6 +9,10 @@ builds that one compiled program and the population state, and runs the
 first chunk from the seed; that chunk is what the reference checks. The
 window then runs whole chunks on the same state until its seconds have
 passed.
+
+The cell's model (bench/models/<model>.py, named by the configuration's
+`model`) sizes the program's config and makes the data and weights; this
+file knows nothing of any one model.
 """
 from __future__ import annotations
 
@@ -24,6 +28,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+import parts
 import population
 import tracing
 import workload
@@ -41,19 +46,6 @@ def log(*parts):
 # the system under test, built from the cell's files
 # ---------------------------------------------------------------------------
 
-def model_config(c: dict):
-    """The program's ModelConfig with the configuration file's sizes."""
-    import dataclasses
-
-    from repro.configs import get_config
-
-    base = get_config(c["model"])
-    return dataclasses.replace(
-        base, cnn_stages=tuple(c["cnn_stages"]), cnn_width=c["cnn_width"],
-        image_size=c["image_size"], image_channels=c["image_channels"],
-        num_classes=c["num_classes"], dtype=c["dtype"])
-
-
 def fl_config(s: dict):
     """The program's FLConfig for the merged sizes of a cell."""
     import dataclasses
@@ -65,22 +57,25 @@ def fl_config(s: dict):
     return FLConfig(comms=CommsConfig(topology=s["topology"]), **kw)
 
 
-def build(sizes: dict, model: str):
+def build(sizes: dict, name: str, model):
+    """The program's config (the registry's `name`, sized by the model's
+    `program_config`), FL config and strategy."""
+    from repro.configs import get_config
     from repro.fl.strategies import make_strategy
 
-    cfg = model_config({**sizes, "model": model})
+    cfg = model.program_config(get_config(name), sizes)
     fl = fl_config(sizes)
     return cfg, fl, make_strategy(sizes["strategy"], cfg, fl,
                                   sizes["steps_per_epoch"])
 
 
-def weights_fn(sizes: dict):
+def weights_fn(sizes: dict, model):
     """jitted keys → {"params", "masks"}: the benchmark's initial
     weights (and DisPFL's masks) on the device."""
 
     @jax.jit
     def make(k_w, k_m):
-        params = population.init_population(sizes, k_w)
+        params = model.init_population(sizes, k_w)
         masks = population.init_masks(params, sizes["dispfl_sparsity"], k_m) \
             if sizes["strategy"] == "dispfl" else None
         return {"params": params, "masks": masks}
@@ -115,12 +110,14 @@ class Program:
     def __init__(self, cell: dict, program_hook=None):
         self.cell, self.sizes = cell, cell["sizes"]
         self.chunk = self.sizes["chunk_rounds"]
+        self.model = workload.model(cell)
         self.ref = importlib.import_module(
             f"reference.{self.sizes['strategy']}")
         if program_hook is not None:
             program_hook()
-        self.cfg, self.fl, self.strat = build(self.sizes, cell["model"])
-        self.make_w = weights_fn(self.sizes)
+        self.cfg, self.fl, self.strat = build(self.sizes, cell["model"],
+                                              self.model)
+        self.make_w = weights_fn(self.sizes, self.model)
         self.compiled = None
 
     def first_chunk(self, seed: int):
@@ -130,9 +127,9 @@ class Program:
             population_mesh
 
         keys = population.seed_keys(seed)
-        data = population.make_train_data(self.sizes, keys["data"])
+        data = self.model.make_train_data(self.sizes, keys["data"])
         state = jax.jit(functools.partial(self.ref.program_state,
-                                          self.strat))(
+                                          self.strat, model=self.model))(
             keys["weights"], self.make_w(keys["weights"], keys["masks"]))
         self.mesh = population_mesh()
         if self.mesh is not None:
@@ -160,11 +157,13 @@ class Program:
 
     def snapshot(self, state, stacked, keys):
         return self.ref.snapshot_program(state, stacked,
-                                         self.init(keys)()["params"])
+                                         self.init(keys)()["params"],
+                                         self.model)
 
     def reference(self, data, keys, quant=None):
         return self.ref.run_reference(self.sizes, data, self.init(keys),
-                                      keys["rounds"], self.chunk, quant)
+                                      keys["rounds"], self.chunk, self.model,
+                                      quant)
 
 
 def enable_cache():
@@ -224,14 +223,16 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
     breakdown = device_extra = None
     if trace:
         hlo = compiled.as_text()
-        tr = tracing.reduce(tracing.extract(TRACE_DIR),
-                            tracing.op_names(hlo), tracing.module_name(hlo))
+        events, module = tracing.extract(TRACE_DIR), tracing.module_name(hlo)
+        tr = tracing.reduce(events, tracing.op_names(hlo), module)
+        tr["part_s"] = parts.part_seconds(events, parts.op_parts(hlo), module)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        info = {"sizes": cell["sizes"], "rounds": rounds,
-                "window_s": window_s, "trace": tr, "peak": peak,
-                "chips": len(devices)}
+        info = {"sizes": cell["sizes"], "model": prog_.model,
+                "rounds": rounds, "window_s": window_s, "trace": tr,
+                "peak": peak, "chips": len(devices)}
         for m in cell["per_layer"]:
-            value = workload.load_module("metrics", m["name"]).read(info)
+            value = workload.load_module("metrics", m["name"],
+                                         cell["bench"]).read(info)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         breakdown = {"device_ops": tr["device_ops"],

@@ -7,7 +7,12 @@ repository defines: round r's key is `fold_in(round_key, r)`, split into
 the strategy's named streams in order; the sampled clients are the first
 round(M * ratio) of a permutation; client i's batch at a step is drawn
 with the i-th of M keys split from that step's key, uniformly with
-replacement from its own training images.
+replacement from its own training examples.
+
+The model is the cell's (bench/models/<model>.py): a batch is the dict of
+arrays its data holds, one client's row of each, and its
+`example_losses(params, batch, sizes, quant)` gives the plain
+reference's loss of each example.
 """
 from __future__ import annotations
 
@@ -18,8 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from reference import resnet
-
 F32 = jnp.float32
 NEG = -1e30
 
@@ -27,13 +30,14 @@ NEG = -1e30
 _JITTED = {}
 
 
-def jitted(round_fn, cfg: dict, quant, **consts):
-    """One compiled reference round per (round, sizes, precision) in a
-    process; the state argument is consumed."""
-    key = (round_fn, json.dumps(cfg, sort_keys=True), quant)
+def jitted(round_fn, cfg: dict, quant, model, **consts):
+    """One compiled reference round per (round, sizes, precision, model)
+    in a process; the state argument is consumed."""
+    key = (round_fn, json.dumps(cfg, sort_keys=True), quant, model)
     if key not in _JITTED:
         _JITTED[key] = jax.jit(functools.partial(
-            round_fn, cfg=cfg, quant=quant, **consts), donate_argnums=(0,))
+            round_fn, cfg=cfg, quant=quant, model=model, **consts),
+            donate_argnums=(0,))
     return _JITTED[key]
 
 
@@ -48,12 +52,16 @@ def sample_clients(key, m: int, ratio: float):
     return idx, jnp.zeros((m,), bool).at[idx].set(True)
 
 
-def client_batch(key, i, data, m: int, batch: int):
+def n_examples(data) -> int:
+    """Examples a client holds: the second axis of every (M, n, ...) leaf."""
+    return jax.tree_util.tree_leaves(data)[0].shape[1]
+
+
+def client_batch(key, i, data, m: int, batch: int) -> dict:
     """Client i's batch under `key`: the i-th of M keys."""
     k = jax.random.split(key, m)[i]
-    n = data["images"].shape[1]
-    j = jax.random.randint(k, (batch,), 0, n)
-    return data["images"][i][j], data["labels"][i][j]
+    j = jax.random.randint(k, (batch,), 0, n_examples(data))
+    return {name: x[i][j] for name, x in data.items()}
 
 
 def sgd(p, g, mu, hp, quant=None):
@@ -71,12 +79,12 @@ def train_rows(loss_of, params, mu, frozen, key, idx, data, *, m, steps,
                hp, quant):
     """`steps` SGD steps of the clients `idx`, side by side: row r of
     `params` (the trained part), `mu` and `frozen` (the rest of the
-    model, or None) is client idx[r]'s. `loss_of(p, frozen, x, y, quant)`
+    model, or None) is client idx[r]'s. `loss_of(p, frozen, batch, quant)`
     → (params, mu, each row's loss at the last step)."""
     keys = jax.random.split(key, steps)
 
-    def one(p, mo, fz, x, y):
-        l, g = jax.value_and_grad(lambda q: loss_of(q, fz, x, y, quant))(p)
+    def one(p, mo, fz, b):
+        l, g = jax.value_and_grad(lambda q: loss_of(q, fz, b, quant))(p)
         upd = jax.tree_util.tree_map(lambda a, b, c: sgd(a, b, c, hp, quant),
                                      p, g, mo)
         p, mo = (jax.tree_util.tree_map(lambda t: t[k], upd,
@@ -86,26 +94,28 @@ def train_rows(loss_of, params, mu, frozen, key, idx, data, *, m, steps,
 
     def step(carry, k):
         p, mo = carry
-        x, y = jax.vmap(lambda i: client_batch(k, i, data, m,
-                                               hp["batch_size"]))(idx)
-        p, mo, l = jax.vmap(one)(p, mo, frozen, x, y)
+        b = jax.vmap(lambda i: client_batch(k, i, data, m,
+                                            hp["batch_size"]))(idx)
+        p, mo, l = jax.vmap(one)(p, mo, frozen, b)
         return (p, mo), l
 
     (params, mu), losses = jax.lax.scan(step, (params, mu), keys)
     return params, mu, losses[-1]
 
 
-def probe_losses(params_rows, data, key, *, m, probe, quant):
+def probe_losses(params_rows, data, key, *, m, probe, losses):
     """(R, M): row r's model on each client's probe batch (Eq. 6), one
-    row at a time over all M probe batches."""
+    row at a time. A row's model runs once, on the M probe batches as one
+    batch of M * P examples (`losses(params, batch)` → each example's
+    loss), and each client's loss is the mean over its own P examples."""
     keys = jax.random.split(key, m)
-    n = data["images"].shape[1]
+    n = n_examples(data)
     j = jax.vmap(lambda k: jax.random.randint(k, (probe,), 0, n))(keys)
-    xs = jax.vmap(lambda a, b: a[b])(data["images"], j)      # (M, P, ...)
-    ys = jax.vmap(lambda a, b: a[b])(data["labels"], j)
+    flat = {name: jax.vmap(lambda a, b: a[b])(x, j).reshape(
+        (m * probe,) + x.shape[2:]) for name, x in data.items()}
 
     def row(p):
-        return jax.vmap(lambda x, y: resnet.loss(p, x, y, quant))(xs, ys)
+        return jnp.mean(losses(p, flat).reshape(m, probe), axis=1)
 
     return jax.lax.map(row, params_rows)
 
@@ -138,6 +148,13 @@ def put_rows(tree, idx, sub, keep=None):
         return x.at[idx].set(s)
 
     return jax.tree_util.tree_map(put, tree, sub)
+
+
+def split(params, header: tuple):
+    """(extractor, header) of a parameter tree, by the header's top-level
+    keys."""
+    return ({k: v for k, v in params.items() if k not in header},
+            {k: v for k, v in params.items() if k in header})
 
 
 def merge(e, h):

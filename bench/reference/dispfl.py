@@ -9,7 +9,7 @@ One round:
    neighbours and itself.
 3. The sampled clients run K_e epochs of SGD on the whole model.
 4. Sampled clients replace their extractor by the plan's average of the
-   trained extractors; headers stay personal.
+   trained extractors; headers (the model's `HEADER`) stay personal.
 5. Mask evolution, per leaf over the whole population: keep the
    `(1 - sparsity) * size` entries of largest magnitude, add the entries
    regrown at random with probability `regrow`, and multiply the
@@ -23,12 +23,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from reference import common as C
-from reference import resnet
 
 STREAMS = ("act", "train", "nbr", "grow")
 
 
-def program_state(strat, key, weights):
+def program_state(strat, key, weights, model):
     """The program's initial state around the benchmark's weights and
     masks: its own init for the optimizer state."""
     return {**strat.init(key), "params": weights["params"],
@@ -40,7 +39,7 @@ def _packed(masks):
             for m in jax.tree_util.tree_leaves(masks)]
 
 
-def snapshot_program(state, stacked, params0):
+def snapshot_program(state, stacked, params0, model):
     return {
         "loss": C.host(stacked["train_loss"]),
         "edges": np.asarray(jax.device_get(stacked["comm_edges"])),
@@ -70,7 +69,7 @@ def evolve(leaf, key, *, sparsity, regrow):
     return leaf * mask.astype(leaf.dtype), mask
 
 
-def _round(state, data, key, r, *, cfg, cand, quant):
+def _round(state, data, key, r, *, cfg, cand, quant, model):
     m = cfg["num_clients"]
     k = min(cfg["peers_per_round"], m - 1)
     hp = {n: cfg[n] for n in ("lr", "momentum", "weight_decay",
@@ -92,7 +91,7 @@ def _round(state, data, key, r, *, cfg, cand, quant):
     # local training of the sampled clients, side by side
     n_steps = cfg["epochs_extractor"] * cfg["steps_per_epoch"]
     p_n, mu_n, losses = C.train_rows(
-        lambda p, fz, x, y, q: resnet.loss(p, x, y, q),
+        lambda p, fz, b, q: jnp.mean(model.example_losses(p, b, cfg, q)),
         C.take_rows(params, idx), C.take_rows(state["mu"], idx), None,
         ks["train"], idx, data, m=m, steps=n_steps, hp=hp, quant=quant)
     act = active[idx]
@@ -100,9 +99,9 @@ def _round(state, data, key, r, *, cfg, cand, quant):
     mu = C.put_rows(state["mu"], idx, mu_n, act)
 
     # the sampled rows mix their extractors
-    ext = {k_: v for k_, v in params.items() if k_ != "head"}
+    ext, hdr = C.split(params, model.HEADER)
     ext = C.put_rows(ext, idx, C.mix_rows(ext, w[idx]), act)
-    params = {**ext, "head": params["head"]}
+    params = C.merge(ext, hdr)
 
     # mask evolution, leaf by leaf in the order of the parameter tree
     leaves, treedef = jax.tree_util.tree_flatten(params)
@@ -115,12 +114,13 @@ def _round(state, data, key, r, *, cfg, cand, quant):
             {"loss": jnp.mean(losses), "edges": edges})
 
 
-def run_reference(cfg, data, init, key, rounds, quant=None):
+def run_reference(cfg, data, init, key, rounds, model, quant=None):
     w0 = init()
     zeros = jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, C.F32),
                                    w0["params"])
     state = {"params": w0["params"], "mask": w0["masks"], "mu": zeros}
-    step = C.jitted(_round, cfg, quant, cand=jnp.asarray(candidates(cfg)))
+    step = C.jitted(_round, cfg, quant, model,
+                    cand=jnp.asarray(candidates(cfg)))
     outs = []
     for r in range(rounds):
         state, out = step(state, data, key, jnp.int32(r))

@@ -14,6 +14,7 @@ One round, for the sampled clients R (round(M * ratio) of them):
 
 The sampled clients train side by side, as a population of clients on
 one chip is trained; each client's result depends only on its own rows.
+The header is the model's `HEADER`, its extractor the rest of the tree.
 """
 from __future__ import annotations
 
@@ -22,23 +23,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from reference import common as C
-from reference import resnet
 
 STREAMS = ("probe", "act", "e", "h", "rand")
 
 
-def program_state(strat, key, weights):
+def program_state(strat, key, weights, model):
     """The program's initial state around the benchmark's weights: its
     own init for everything else (optimizer state, context arrays)."""
-    params = weights["params"]
-    ext = {k: v for k, v in params.items() if k != "head"}
-    return strat.init(key)._replace(extractor=ext,
-                                    header={"head": params["head"]})
+    ext, hdr = C.split(weights["params"], model.HEADER)
+    return strat.init(key)._replace(extractor=ext, header=hdr)
 
 
-def snapshot_program(state, stacked, params0):
+def snapshot_program(state, stacked, params0, model):
     """What the comparison reads of the program after its first chunk."""
-    e0 = {k: v for k, v in params0.items() if k != "head"}
     return {
         "loss_e": C.host(stacked["train_loss_e"]),
         "loss_h": C.host(stacked["train_loss_h"]),
@@ -46,13 +43,13 @@ def snapshot_program(state, stacked, params0):
         "s_l_mean": C.host(stacked["s_l_mean"]),
         "loss_matrix": C.host(state.loss_matrix),
         "change": C.host(C.change_norms(
-            (state.extractor, state.header), (e0, {"head": params0["head"]}))),
+            (state.extractor, state.header), C.split(params0, model.HEADER))),
         "momentum": C.host(C.norms((state.opt_e["mu"], state.opt_h["mu"]))),
         "round": int(state.round),
     }
 
 
-def _round(state, data, key, r, *, cfg, quant):
+def _round(state, data, key, r, *, cfg, quant, model):
     m, k = cfg["num_clients"], min(cfg["peers_per_round"],
                                    cfg["num_clients"] - 1)
     spe = cfg["steps_per_epoch"]
@@ -63,9 +60,12 @@ def _round(state, data, key, r, *, cfg, quant):
     e, h = state["e"], state["h"]
 
     # Eq. 6 on the sampled rows
+    def losses(p, batch, q=quant):
+        return model.example_losses(p, batch, cfg, q)
+
     rows = C.probe_losses(C.take_rows(C.merge(e, h), idx), data,
                           ks["probe"], m=m, probe=cfg["probe_size"],
-                          quant=quant)
+                          losses=losses)
     s_l = state["loss_matrix"].at[idx].set(rows)
 
     # Eq. 7-9 and top-k
@@ -95,11 +95,11 @@ def _round(state, data, key, r, *, cfg, quant):
     n_h = cfg["epochs_header"] * spe
     h_r = C.take_rows(h, idx)
     e_n, mue_n, le = C.train_rows(
-        lambda p, fz, x, y, q: resnet.loss(C.merge(p, fz), x, y, q),
+        lambda p, fz, b, q: jnp.mean(losses(C.merge(p, fz), b, q)),
         agg, C.take_rows(state["mu_e"], idx), h_r, ks["e"], idx, data,
         m=m, steps=n_e, hp=hp, quant=quant)
     h_n, muh_n, lh = C.train_rows(
-        lambda p, fz, x, y, q: resnet.loss(C.merge(fz, p), x, y, q),
+        lambda p, fz, b, q: jnp.mean(losses(C.merge(fz, p), b, q)),
         h_r, C.take_rows(state["mu_h"], idx), e_n, ks["h"], idx, data,
         m=m, steps=n_h, hp=hp, quant=quant)
     act = active[idx]
@@ -117,29 +117,25 @@ def _round(state, data, key, r, *, cfg, quant):
         "s_l_mean": jnp.mean(rows)}
 
 
-def run_reference(cfg, data, init, key, rounds, quant=None):
+def run_reference(cfg, data, init, key, rounds, model, quant=None):
     """`rounds` reference rounds from the benchmark's weights → the same
     readings as `snapshot_program`. `init()` makes the weights afresh
     (the rounds consume their buffers)."""
     m = cfg["num_clients"]
-    params0 = init()["params"]
-    e0 = {k: v for k, v in params0.items() if k != "head"}
-    h0 = {"head": params0["head"]}
+    e0, h0 = C.split(init()["params"], model.HEADER)
     zeros = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda x: jnp.zeros(x.shape, C.F32), t)
     state = {"e": e0, "h": h0, "mu_e": zeros(e0), "mu_h": zeros(h0),
              "loss_matrix": jnp.zeros((m, m), C.F32),
              "last": jnp.full((m, m), -1, jnp.int32),
              "round": jnp.zeros((), jnp.int32)}
-    step = C.jitted(_round, cfg, quant)
+    step = C.jitted(_round, cfg, quant, model)
     outs = []
     for r in range(rounds):
         state, out = step(state, data, key, jnp.int32(r))
         outs.append(out)
     outs = jax.device_get(outs)
-    params0 = init()["params"]
-    e0 = {k: v for k, v in params0.items() if k != "head"}
-    h0 = {"head": params0["head"]}
+    e0, h0 = C.split(init()["params"], model.HEADER)
     return {
         "loss_e": np.array([o["loss_e"] for o in outs], np.float64),
         "loss_h": np.array([o["loss_h"] for o in outs], np.float64),

@@ -1,5 +1,5 @@
 """Imported first by the benchmark's tests: puts bench/ and src/ on the
-path, and holds the tiny cell the CPU can run."""
+path, and makes the tiny cells the CPU can run."""
 import os
 import sys
 
@@ -9,18 +9,39 @@ for p in (BENCH, os.path.join(ROOT, "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# a cell small enough for the CPU: the published block (widths from 64,
-# eight-channel groups) at two stages and 8x8 images, 6 clients
-TINY = dict(cnn_stages=[1], cnn_width=64, image_size=8, num_clients=6,
-            client_sample_ratio=0.5, peers_per_round=2, probe_size=4,
-            batch_size=8, samples_per_class=10, steps_per_epoch=1,
-            epochs_extractor=1, epochs_header=1, chunk_rounds=2)
 
-
-def tiny_cell(name: str) -> dict:
-    """Cell `name` of BENCHMARK.json at the tiny size."""
+def tiny_cell(name: str, root: str = ROOT) -> dict:
+    """Cell `name` of `root`'s BENCHMARK.json at its model's tiny size
+    (the model's `TINY`)."""
     import workload
 
-    cell = workload.cell(name)
-    cell["sizes"].update(TINY)
+    cell = workload.cell(name, root)
+    cell["sizes"].update(workload.model(cell).TINY)
     return cell
+
+
+def check_against_program(sizes: dict, model) -> None:
+    """A model's file against the registry (`check_config`), its HEADER
+    against what the program's split keeps per client, and one client's
+    weights as the model makes them against the layout, shapes and dtype
+    of the program's own init."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import model as program
+    from repro.models import split
+
+    base = get_config(sizes["model"])
+    model.check_config(sizes, base)
+    cfg = model.program_config(base, sizes)
+    assert tuple(model.HEADER) == tuple(split.header_keys(cfg))
+    assert set(model.HEADER) <= set(model.param_shapes(sizes))
+    theirs = jax.eval_shape(lambda k: program.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    ours = jax.eval_shape(lambda k: model.init_population(
+        {**sizes, "num_clients": 1}, k), jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_structure(ours) \
+        == jax.tree_util.tree_structure(theirs)
+    for a, b in zip(jax.tree_util.tree_leaves(ours),
+                    jax.tree_util.tree_leaves(theirs)):
+        assert (a.shape[1:], a.dtype) == (b.shape, b.dtype)

@@ -1,14 +1,13 @@
 """The correctness check on the CPU at a tiny size: a sound run passes the
 cell's limits, planted faults of the timed path fail them, and the
-control (the reference in float8 in the program's place) reads apart."""
+control (the reference in the model's control precision, float8 here, in
+the program's place) reads apart."""
 import bench_paths
 import jax
 import pytest
 
 import faults
 import harness
-import population
-from reference import resnet
 
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 CELLS = ("pfeddst.m16", "dispfl-ring.m16")
@@ -33,6 +32,20 @@ def test_sound_run_is_correct(name):
     assert set(res["metrics"]) == {"round_s", "peak_hbm_gib", "setup_s"}
 
 
+def test_traced_run_reads_per_layer_metrics():
+    """--trace 1 on the CPU: the readers get the cell's model and the
+    trace's stage and part times; those that find nothing on a CPU trace
+    (no TPU device line) leave their metric out."""
+    cell = bench_paths.tiny_cell("pfeddst.m16")
+    res = harness.run(cell, seed=4_000_000_124, seconds=0.0, trace=True,
+                      t0=0.0, devices=jax.devices()[:1], peak=PEAK)
+    assert res["correct"], res["checks"]
+    assert "mfu" in res["metrics"]
+    assert set(res["metrics"]) <= {m["name"] for m in cell["per_layer"]}
+    assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert {"busy_s", "window_s"} <= set(res["device"])
+
+
 def _sound_and_control(name, seed=77):
     """The tiny cell's sound reading and its control's, each compared
     with the reference."""
@@ -40,8 +53,8 @@ def _sound_and_control(name, seed=77):
     state, stacked, data, keys = prog.first_chunk(seed)
     ref = prog.reference(data, keys)
     sound = prog.ref.compare(prog.snapshot(state, stacked, keys), ref)
-    ctl = prog.ref.compare(prog.reference(data, keys, quant=resnet.fp8_quant),
-                           ref)
+    ctl = prog.ref.compare(
+        prog.reference(data, keys, quant=prog.model.control_quant), ref)
     return sound, ctl
 
 

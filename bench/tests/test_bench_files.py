@@ -90,10 +90,10 @@ def test_config_files(c):
         assert k in sizes and NAME.match(k) and not WIDTH.search(k), k
     from repro.configs import get_config
 
-    base = get_config(sizes["model"])
-    assert tuple(sizes["cnn_stages"]) == base.cnn_stages
-    assert sizes["cnn_width"] == base.cnn_width
-    assert sizes["dtype"] == base.dtype
+    # the file's widths and dtype against the registry, as its model
+    # (bench/models/<model>.py) reads them; raises on a mismatch
+    model = workload.load_module("models", sizes["model"])
+    model.check_config(sizes, get_config(sizes["model"]))
 
 
 def test_every_config_and_traffic_is_used():

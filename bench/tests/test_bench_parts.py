@@ -1,7 +1,8 @@
 """The `part:` scopes inside the engine's stages: read from the compiled
 chunk on the CPU, reduced from hand-made events, and reduced from a
 trace recorded on a TPU v5e (one chunk of two rounds of the tiny pfeddst
-cell, bench/tests/record_fixture.py)."""
+cell, bench/tests/record_fixture.py), and read by the per-layer metrics
+`probe_ms` and `select_ms`."""
 import bench_paths
 import json
 import os
@@ -11,6 +12,7 @@ import pytest
 import harness
 import parts as P
 import tracing as T
+import workload
 
 HERE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
 STAGES = {"score_select", "aggregate", "phase_e", "phase_h",
@@ -110,3 +112,39 @@ def test_recorded_parts():
     assert r["stage_s"] == pytest.approx(fx["reduced"]["stage_s"],
                                          rel=1e-9)
     assert parts == pytest.approx(fx["part_s"], rel=1e-9)
+
+
+def read(metric, trace, rounds):
+    return workload.load_module("metrics", metric).read(
+        {"trace": trace, "rounds": rounds})
+
+
+def test_part_readers():
+    """probe_ms and select_ms: a part's device time per round, summed
+    over devices and averaged over them; nothing where the trace holds
+    no such part."""
+    ev = events()
+    tr = T.reduce(ev, T.op_names(HLO), M)
+    tr["part_s"] = P.part_seconds(ev, P.op_parts(HLO), M)
+    assert read("probe_ms", tr, 2) == pytest.approx(1.5)
+    assert read("select_ms", tr, 2) == pytest.approx(0.25)
+    tr["part_s"] = {"update_context/telemetry": 0.0005}
+    assert read("probe_ms", tr, 2) is None
+    assert read("select_ms", tr, 2) is None
+
+
+def test_recorded_parts_feed_the_readers():
+    """On the recorded chip trace, the probe and the selection hold
+    nearly all of score_select_ms (the telemetry part the rest)."""
+    fx = load()
+    names = {k: tuple(v) for k, v in fx["names"].items()}
+    tr = T.reduce(fx["events"], names, fx["module"])
+    tr["part_s"] = P.part_seconds(fx["events"], fx["parts"], fx["module"])
+    probe, select, score = (read(m, tr, 2) for m in (
+        "probe_ms", "select_ms", "score_select_ms"))
+    assert probe == pytest.approx(
+        1000 * fx["part_s"]["score_select/eq6_probe"] / 2, rel=1e-9)
+    assert select == pytest.approx(
+        1000 * fx["part_s"]["score_select/eq79_select"] / 2, rel=1e-9)
+    assert 0 < select < probe
+    assert 0.9 * score <= probe + select <= score

@@ -4,12 +4,17 @@ Everything that belongs to one configuration, traffic mix, metric or
 strategy sits in a file of its own, found by name:
 
     bench/configs/<config>.json       sizes of the model and population
+    bench/models/<model>.py           the model: its program config, data,
+                                      weights, header, reference loss,
+                                      FLOPs (the configuration's `model`)
     bench/traffic/<traffic>.json      strategy, topology, chunk size
     bench/limits/<workload>.json      limits of the correctness check
     bench/metrics/<metric>.py         reader of one per-layer metric
     bench/reference/<strategy>.py     plain reference of one strategy
 
-so a later change adds a cell by adding files and entries only.
+so a later change adds a cell, or a model, by adding files and entries
+only. A benchmark root is a directory that holds BENCHMARK.json and
+these files under bench/.
 """
 from __future__ import annotations
 
@@ -38,34 +43,49 @@ def read_json(path: str) -> dict:
 
 def cell(name: str, root: str = ROOT) -> dict:
     """Everything one run of cell `name` needs: the merged sizes
-    (configuration, then traffic), its metrics by kind, its limits."""
+    (configuration, then traffic), its metrics by kind, its limits, and
+    the bench/ directory of `root` that holds its files."""
     bm = benchmark(root)
+    bench = os.path.join(root, "bench")
     wl = {w["name"]: w for w in bm["workloads"]}
     if name not in wl:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = wl[name]
     conf = next(c for c in bm["configs"] if c["name"] == w["config"])
     sizes = read_json(os.path.join(root, conf["file"]))
-    traffic = read_json(os.path.join(HERE, "traffic", f"{w['traffic']}.json"))
+    traffic = read_json(os.path.join(bench, "traffic",
+                                     f"{w['traffic']}.json"))
 
     def applies(metric):
         return "workloads" not in metric or name in metric["workloads"]
 
     return {
         "name": name, "chips": w["chips"], "model": sizes["model"],
+        "bench": bench,
         "sizes": {**{k: v for k, v in sizes.items() if k not in META},
                   **traffic},
         "end_to_end": [m for m in bm["end_to_end"] if applies(m)],
         "per_layer": [m for m in bm["per_layer"] if applies(m)],
-        "limits": read_json(os.path.join(HERE, "limits", f"{name}.json")),
+        "limits": read_json(os.path.join(bench, "limits", f"{name}.json")),
     }
 
 
-def load_module(kind: str, name: str):
-    """bench/<kind>/<name>.py as a module (the per-layer metric readers)."""
-    path = os.path.join(HERE, kind, f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def model(c: dict):
+    """The cell's model: bench/models/<model>.py of its root."""
+    return load_module("models", c["model"], c["bench"])
+
+
+_LOADED = {}
+
+
+def load_module(kind: str, name: str, bench: str = HERE):
+    """<bench>/<kind>/<name>.py as a module (a model, a per-layer metric
+    reader), loaded once a process, as an import is."""
+    path = os.path.join(bench, kind, f"{name}.py")
+    if path not in _LOADED:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
