@@ -70,15 +70,19 @@ def build(sizes: dict, name: str, model):
 
 
 def weights_fn(sizes: dict, model):
-    """jitted keys → {"params", "masks"}: the benchmark's initial
-    weights (and DisPFL's masks) on the device."""
+    """jitted keys → {"params", "masks", "shared"}: the benchmark's
+    initial weights of every client, DisPFL's masks, and the weights that
+    every client shares (None where the model has none), on the device."""
+    shared = workload.has_shared(model)
 
     @jax.jit
     def make(k_w, k_m):
         params = model.init_population(sizes, k_w)
         masks = population.init_masks(params, sizes["dispfl_sparsity"], k_m) \
             if sizes["strategy"] == "dispfl" else None
-        return {"params": params, "masks": masks}
+        return {"params": params, "masks": masks,
+                "shared": model.init_shared(
+                    sizes, population.shared_key(k_w)) if shared else None}
 
     return make
 
@@ -119,6 +123,16 @@ class Program:
                                               self.model)
         self.make_w = weights_fn(self.sizes, self.model)
         self.compiled = None
+        if workload.has_shared(self.model):
+            # traced, not compiled: a program whose state has no place for
+            # the shared weights stops here, before any compile
+            jax.eval_shape(lambda k: self.initial_state(k, self.make_w(k, k)),
+                           jax.random.PRNGKey(0))
+
+    def initial_state(self, k_w, weights):
+        """The program's initial state around the benchmark's weights."""
+        return self.ref.program_state(self.strat, k_w, weights,
+                                      model=self.model)
 
     def first_chunk(self, seed: int):
         """Inputs, weights and state from the seed, then the first chunk
@@ -128,9 +142,12 @@ class Program:
 
         keys = population.seed_keys(seed)
         data = self.model.make_train_data(self.sizes, keys["data"])
-        state = jax.jit(functools.partial(self.ref.program_state,
-                                          self.strat, model=self.model))(
+        state = jax.jit(self.initial_state)(
             keys["weights"], self.make_w(keys["weights"], keys["masks"]))
+        # no mesh on one chip; on four, the shared weights (no client
+        # axis) are to be replicated, which place_population does not do
+        # for a leaf whose first axis happens to be M: left to the change
+        # that runs such a model on four chips
         self.mesh = population_mesh()
         if self.mesh is not None:
             state = place_population(state, self.fl.num_clients, self.mesh)
@@ -156,9 +173,9 @@ class Program:
         return functools.partial(self.make_w, keys["weights"], keys["masks"])
 
     def snapshot(self, state, stacked, keys):
-        return self.ref.snapshot_program(state, stacked,
-                                         self.init(keys)()["params"],
-                                         self.model)
+        w0 = self.init(keys)()
+        return self.ref.snapshot_program(state, stacked, w0["params"],
+                                         self.model, w0["shared"])
 
     def reference(self, data, keys, quant=None):
         return self.ref.run_reference(self.sizes, data, self.init(keys),

@@ -2,9 +2,10 @@
 
 A run's data and initial weights are the cell's model's
 (bench/models/<model>.py: `make_train_data`, `init_population`), each
-from its own key of `seed_keys`. Nothing here imports the program: the
-reference starts from exactly these arrays, and the program's state is
-built around them.
+from its own key of `seed_keys`; the weights that every client shares,
+where the model has them (`init_shared`), from `shared_key`. Nothing
+here imports the program: the reference starts from exactly these
+arrays, and the program's state is built around them.
 """
 from __future__ import annotations
 
@@ -22,6 +23,18 @@ def seed_keys(seed: int) -> dict:
     """The named keys of one run: data, weights, masks, rounds."""
     ks = jax.random.split(base_key(seed), 4)
     return dict(zip(("data", "weights", "masks", "rounds"), ks))
+
+
+# folded into the weights' key for the weights every client shares; a fifth
+# split of the base key would change the four keys above, and with them
+# every cell's data and weights
+SHARED_FOLD = 0x5348
+
+
+def shared_key(weights_key):
+    """The key of the weights every client shares, from `seed_keys`'s
+    weights key."""
+    return jax.random.fold_in(weights_key, SHARED_FOLD)
 
 
 def init_masks(params, sparsity: float, key):

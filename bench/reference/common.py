@@ -12,7 +12,9 @@ replacement from its own training examples.
 The model is the cell's (bench/models/<model>.py): a batch is the dict of
 arrays its data holds, one client's row of each, and its
 `example_losses(params, batch, sizes, quant)` gives the plain
-reference's loss of each example.
+reference's loss of each example. Where the model brings weights that
+every client shares, every loss reads them as given (`bind_losses`); the
+reference keeps no optimizer state over them and never updates them.
 """
 from __future__ import annotations
 
@@ -39,6 +41,36 @@ def jitted(round_fn, cfg: dict, quant, model, **consts):
             round_fn, cfg=cfg, quant=quant, model=model, **consts),
             donate_argnums=(0,))
     return _JITTED[key]
+
+
+NO_SHARED = "the program cannot hold weights that every client shares"
+
+
+def hold_shared(state, shared):
+    """The program's initial state with the shared weights in its place
+    `shared`, a field of a named tuple or a key of a dict; the state as
+    it is where there are none. Raises ValueError where the state has no
+    such place."""
+    if shared is None:
+        return state
+    if isinstance(state, dict) and "shared" in state:
+        return {**state, "shared": shared}
+    if "shared" in getattr(state, "_fields", ()):
+        return state._replace(shared=shared)
+    raise ValueError(f"{NO_SHARED}: its state ({type(state).__name__}) has "
+                     f"no place `shared`")
+
+
+def bind_losses(model, cfg: dict, quant, shared):
+    """`losses(p, batch, q=quant)`: the model's loss of each example of
+    one client's batch under its weights `p`, and the shared weights
+    where the model has them."""
+    kw = {} if shared is None else {"shared": shared}
+
+    def losses(p, batch, q=quant):
+        return model.example_losses(p, batch, cfg, q, **kw)
+
+    return losses
 
 
 def streams(key, r, names):
@@ -177,6 +209,30 @@ def change_norms(after, before):
 def norms(tree):
     return [jnp.sqrt(jnp.sum(jnp.square(a.astype(F32))))
             for a in jax.tree_util.tree_leaves(tree)]
+
+
+@jax.jit
+def largest_change(after, before):
+    """Largest |after - before| over every entry of two trees of one
+    layout (not finite where an entry is not)."""
+    gaps = jax.tree_util.tree_map(
+        lambda a, b: jnp.max(jnp.abs(a.astype(F32) - b.astype(F32))),
+        after, before)
+    return jnp.max(jnp.stack(jax.tree_util.tree_leaves(gaps)))
+
+
+def shared_reading(shared, shared0) -> dict:
+    """{"shared_changed": the largest change of the shared weights from
+    the seed's}, or {} where the model has none."""
+    if shared0 is None:
+        return {}
+    return {"shared_changed": float(largest_change(shared, shared0))}
+
+
+def shared_number(prog) -> dict:
+    """The compared `shared_changed`, where the program's snapshot reads
+    one (the reference never updates the shared weights)."""
+    return {k: prog[k] for k in ("shared_changed",) if k in prog}
 
 
 def host(xs) -> np.ndarray:

@@ -14,6 +14,9 @@ One round:
    `(1 - sparsity) * size` entries of largest magnitude, add the entries
    regrown at random with probability `regrow`, and multiply the
    parameters by the new mask.
+
+Weights that every client shares, where the model has them, enter every
+loss and are neither masked, trained nor mixed.
 """
 from __future__ import annotations
 
@@ -29,9 +32,10 @@ STREAMS = ("act", "train", "nbr", "grow")
 
 def program_state(strat, key, weights, model):
     """The program's initial state around the benchmark's weights and
-    masks: its own init for the optimizer state."""
-    return {**strat.init(key), "params": weights["params"],
-            "mask": weights["masks"]}
+    masks: its own init for the optimizer state; the shared weights,
+    where there are any, under its key `shared`."""
+    return C.hold_shared({**strat.init(key), "params": weights["params"],
+                          "mask": weights["masks"]}, weights["shared"])
 
 
 def _packed(masks):
@@ -39,7 +43,7 @@ def _packed(masks):
             for m in jax.tree_util.tree_leaves(masks)]
 
 
-def snapshot_program(state, stacked, params0, model):
+def snapshot_program(state, stacked, params0, model, shared0=None):
     return {
         "loss": C.host(stacked["train_loss"]),
         "edges": np.asarray(jax.device_get(stacked["comm_edges"])),
@@ -47,6 +51,8 @@ def snapshot_program(state, stacked, params0, model):
         "change": C.host(C.change_norms(state["params"], params0)),
         "momentum": C.host(C.norms(state["opt"]["mu"])),
         "round": int(state["round"]),
+        **C.shared_reading(None if shared0 is None else state["shared"],
+                           shared0),
     }
 
 
@@ -69,7 +75,7 @@ def evolve(leaf, key, *, sparsity, regrow):
     return leaf * mask.astype(leaf.dtype), mask
 
 
-def _round(state, data, key, r, *, cfg, cand, quant, model):
+def _round(state, data, key, r, shared, *, cfg, cand, quant, model):
     m = cfg["num_clients"]
     k = min(cfg["peers_per_round"], m - 1)
     hp = {n: cfg[n] for n in ("lr", "momentum", "weight_decay",
@@ -90,8 +96,9 @@ def _round(state, data, key, r, *, cfg, cand, quant, model):
 
     # local training of the sampled clients, side by side
     n_steps = cfg["epochs_extractor"] * cfg["steps_per_epoch"]
+    example_losses = C.bind_losses(model, cfg, quant, shared)
     p_n, mu_n, losses = C.train_rows(
-        lambda p, fz, b, q: jnp.mean(model.example_losses(p, b, cfg, q)),
+        lambda p, fz, b, q: jnp.mean(example_losses(p, b, q)),
         C.take_rows(params, idx), C.take_rows(state["mu"], idx), None,
         ks["train"], idx, data, m=m, steps=n_steps, hp=hp, quant=quant)
     act = active[idx]
@@ -123,7 +130,7 @@ def run_reference(cfg, data, init, key, rounds, model, quant=None):
                     cand=jnp.asarray(candidates(cfg)))
     outs = []
     for r in range(rounds):
-        state, out = step(state, data, key, jnp.int32(r))
+        state, out = step(state, data, key, jnp.int32(r), w0["shared"])
         outs.append(out)
     outs = jax.device_get(outs)
     params0 = init()["params"]
@@ -153,4 +160,5 @@ def compare(prog, ref) -> dict:
         "momentum": float(mom.max()), "momentum_median": float(np.median(mom)),
         "change": float(chg.max()), "change_median": float(np.median(chg)),
         "rounds": float(abs(prog["round"] - ref["round"])),
+        **C.shared_number(prog),
     }

@@ -14,7 +14,9 @@ One round, for the sampled clients R (round(M * ratio) of them):
 
 The sampled clients train side by side, as a population of clients on
 one chip is trained; each client's result depends only on its own rows.
-The header is the model's `HEADER`, its extractor the rest of the tree.
+The header is the model's `HEADER`, its extractor the rest of the tree;
+weights that every client shares, where the model has them, enter every
+loss and are never trained.
 """
 from __future__ import annotations
 
@@ -29,13 +31,16 @@ STREAMS = ("probe", "act", "e", "h", "rand")
 
 def program_state(strat, key, weights, model):
     """The program's initial state around the benchmark's weights: its
-    own init for everything else (optimizer state, context arrays)."""
+    own init for everything else (optimizer state, context arrays); the
+    shared weights, where there are any, in its field `shared`."""
     ext, hdr = C.split(weights["params"], model.HEADER)
-    return strat.init(key)._replace(extractor=ext, header=hdr)
+    return C.hold_shared(strat.init(key)._replace(extractor=ext, header=hdr),
+                         weights["shared"])
 
 
-def snapshot_program(state, stacked, params0, model):
-    """What the comparison reads of the program after its first chunk."""
+def snapshot_program(state, stacked, params0, model, shared0=None):
+    """What the comparison reads of the program after its first chunk
+    (`shared0`: the seed's shared weights, where there are any)."""
     return {
         "loss_e": C.host(stacked["train_loss_e"]),
         "loss_h": C.host(stacked["train_loss_h"]),
@@ -46,10 +51,12 @@ def snapshot_program(state, stacked, params0, model):
             (state.extractor, state.header), C.split(params0, model.HEADER))),
         "momentum": C.host(C.norms((state.opt_e["mu"], state.opt_h["mu"]))),
         "round": int(state.round),
+        **C.shared_reading(None if shared0 is None else state.shared,
+                           shared0),
     }
 
 
-def _round(state, data, key, r, *, cfg, quant, model):
+def _round(state, data, key, r, shared, *, cfg, quant, model):
     m, k = cfg["num_clients"], min(cfg["peers_per_round"],
                                    cfg["num_clients"] - 1)
     spe = cfg["steps_per_epoch"]
@@ -60,9 +67,7 @@ def _round(state, data, key, r, *, cfg, quant, model):
     e, h = state["e"], state["h"]
 
     # Eq. 6 on the sampled rows
-    def losses(p, batch, q=quant):
-        return model.example_losses(p, batch, cfg, q)
-
+    losses = C.bind_losses(model, cfg, quant, shared)
     rows = C.probe_losses(C.take_rows(C.merge(e, h), idx), data,
                           ks["probe"], m=m, probe=cfg["probe_size"],
                           losses=losses)
@@ -122,7 +127,9 @@ def run_reference(cfg, data, init, key, rounds, model, quant=None):
     readings as `snapshot_program`. `init()` makes the weights afresh
     (the rounds consume their buffers)."""
     m = cfg["num_clients"]
-    e0, h0 = C.split(init()["params"], model.HEADER)
+    w0 = init()
+    shared = w0["shared"]
+    e0, h0 = C.split(w0["params"], model.HEADER)
     zeros = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda x: jnp.zeros(x.shape, C.F32), t)
     state = {"e": e0, "h": h0, "mu_e": zeros(e0), "mu_h": zeros(h0),
@@ -132,7 +139,7 @@ def run_reference(cfg, data, init, key, rounds, model, quant=None):
     step = C.jitted(_round, cfg, quant, model)
     outs = []
     for r in range(rounds):
-        state, out = step(state, data, key, jnp.int32(r))
+        state, out = step(state, data, key, jnp.int32(r), shared)
         outs.append(out)
     outs = jax.device_get(outs)
     e0, h0 = C.split(init()["params"], model.HEADER)
@@ -176,4 +183,5 @@ def compare(prog, ref) -> dict:
         "momentum": float(mom.max()), "momentum_median": float(np.median(mom)),
         "change": float(chg.max()), "change_median": float(np.median(chg)),
         "rounds": float(abs(prog["round"] - ref["round"])),
+        **C.shared_number(prog),
     }

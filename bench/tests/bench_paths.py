@@ -1,6 +1,8 @@
 """Imported first by the benchmark's tests: puts bench/ and src/ on the
 path, and makes the tiny cells the CPU can run."""
+import json
 import os
+import shutil
 import sys
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -18,6 +20,30 @@ def tiny_cell(name: str, root: str = ROOT) -> dict:
     cell = workload.cell(name, root)
     cell["sizes"].update(workload.model(cell).TINY)
     return cell
+
+
+def build_root(dest: str, fixture: str) -> str:
+    """A benchmark root at `dest`: bench/ and BENCHMARK.json as they are,
+    plus the files (models/, configs/, limits/) and the BENCHMARK.json
+    entries (entries.json) of the fixture directory `fixture`."""
+    import workload
+
+    bench = os.path.join(dest, "bench")
+    shutil.copytree(BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    for kind in ("models", "configs", "limits"):
+        for f in os.listdir(os.path.join(fixture, kind)):
+            assert not os.path.exists(os.path.join(bench, kind, f)), f
+            shutil.copy(os.path.join(fixture, kind, f),
+                        os.path.join(bench, kind, f))
+    bm = workload.benchmark()
+    with open(os.path.join(fixture, "entries.json")) as fh:
+        entries = json.load(fh)
+    for section, added in entries.items():
+        bm[section] += added
+    with open(os.path.join(dest, "BENCHMARK.json"), "w") as fh:
+        json.dump(bm, fh)
+    return dest
 
 
 def check_against_program(sizes: dict, model) -> None:

@@ -11,9 +11,7 @@ faults of the timed path are not, and the control (the reference in
 bfloat16 in the program's place) reads apart from sound runs.
 """
 import bench_paths
-import json
 import os
-import shutil
 
 import jax
 import pytest
@@ -27,30 +25,10 @@ CELLS = ("pfeddst.lm", "dispfl.lm")
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
-def build_root(dest: str) -> str:
-    """A benchmark root at `dest`: bench/ and BENCHMARK.json as they are,
-    plus the fixture's files and entries."""
-    bench = os.path.join(dest, "bench")
-    shutil.copytree(bench_paths.BENCH, bench,
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    for kind in ("models", "configs", "limits"):
-        for f in os.listdir(os.path.join(FIXTURE, kind)):
-            assert not os.path.exists(os.path.join(bench, kind, f)), f
-            shutil.copy(os.path.join(FIXTURE, kind, f),
-                        os.path.join(bench, kind, f))
-    bm = workload.benchmark()
-    with open(os.path.join(FIXTURE, "entries.json")) as fh:
-        entries = json.load(fh)
-    for section, added in entries.items():
-        bm[section] += added
-    with open(os.path.join(dest, "BENCHMARK.json"), "w") as fh:
-        json.dump(bm, fh)
-    return dest
-
-
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    return build_root(str(tmp_path_factory.mktemp("lm_root")))
+    return bench_paths.build_root(str(tmp_path_factory.mktemp("lm_root")),
+                                  FIXTURE)
 
 
 def run(cell, seed, hook=None):

@@ -15,6 +15,18 @@ strategy sits in a file of its own, found by name:
 so a later change adds a cell, or a model, by adding files and entries
 only. A benchmark root is a directory that holds BENCHMARK.json and
 these files under bench/.
+
+A model may also bring weights that every client shares and none
+trains, such as a frozen trunk under per-client adapters: its
+`init_shared(sizes, key)` makes them, one tree with no client axis in
+the configuration's dtype (`shared_shapes(sizes)` gives its layout), and
+its `example_losses(params, batch, sizes, quant, shared=...)` reads them,
+each operation at float32. The per-client tree (`init_population`,
+`param_shapes`) is then what a client trains, and `extractor_params` and
+`header_width` count one client's share. `flops(sizes)` is unchanged in
+form: its `train_extractor` and `train_full` count the backward pass
+through the frozen weights down to the lowest trained parameter. A model
+without `init_shared` has no shared weights.
 """
 from __future__ import annotations
 
@@ -73,6 +85,11 @@ def cell(name: str, root: str = ROOT) -> dict:
 def model(c: dict):
     """The cell's model: bench/models/<model>.py of its root."""
     return load_module("models", c["model"], c["bench"])
+
+
+def has_shared(model) -> bool:
+    """Whether the model brings weights that every client shares."""
+    return hasattr(model, "init_shared")
 
 
 _LOADED = {}
